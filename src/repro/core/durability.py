@@ -137,7 +137,7 @@ class WalWriter:
         self.epoch = int(epoch)
         self.seq = int(seq)          # next record's global sequence number
         self.fsync = fsync
-        self._dirty = False          # appends not yet fsync'd ("batch" mode)
+        self._unsynced = 0           # bytes appended, not yet fsync'd
         self._f = open(_segment_path(directory, self.epoch), "ab")
         if self._f.tell() == 0:
             self._f.write(_SEG_HDR.pack(SEG_MAGIC, SEG_VERSION, self.epoch))
@@ -173,11 +173,12 @@ class WalWriter:
             raise
         self._f.write(hdr)
         self._f.write(payload)
+        self._unsynced += len(hdr) + len(payload)
         if self.fsync == "always":
             self._f.flush()
             os.fsync(self._f.fileno())
-        else:
-            self._dirty = True          # flushed + fsync'd at sync()/close()
+            self._unsynced = 0
+        # otherwise flushed + fsync'd at sync()/close()
         self.seq += 1
         kind = "slab" if rtype == REC_SLAB else "map"
         obs.count("f2_wal_records_total", help="WAL records appended",
@@ -189,14 +190,19 @@ class WalWriter:
     def log_slab(self, keys, ops, vals, map_version: int):
         """One client batch's full input.  Write-free batches (reads/noops
         only) are skipped: they cannot change logical content, and replay
-        re-derives any internal deferral rounds from the batch itself."""
-        ops_np = np.asarray(ops, np.int32)
-        writes = ((ops_np == OP_UPSERT) | (ops_np == OP_RMW)
-                  | (ops_np == OP_DELETE))
-        if not writes.any():
-            return
-        payload = self._encode(keys, ops_np, vals)
-        self._append(REC_SLAB, map_version, payload)
+        re-derives any internal deferral rounds from the batch itself.
+        The span's `bytes` is the record this batch appends if it holds a
+        write."""
+        B, V = np.shape(vals)
+        with obs.span("wal.log_slab", cat="durability",
+                      bytes=_REC_HDR.size + _PAY_HDR.size + 4 * B * (2 + V)):
+            ops_np = np.asarray(ops, np.int32)
+            writes = ((ops_np == OP_UPSERT) | (ops_np == OP_RMW)
+                      | (ops_np == OP_DELETE))
+            if not writes.any():
+                return
+            payload = self._encode(keys, ops_np, vals)
+            self._append(REC_SLAB, map_version, payload)
 
     def log_map(self, new_map, map_version: int, keys, ops, vals):
         """One migration: the post-flip bucket map plus the drained
@@ -212,19 +218,22 @@ class WalWriter:
         """Group-commit barrier: fsync any buffered appends.  `DurableKV`
         calls this after every client-visible batch, before the statuses
         are returned — an op is acked only once its record is durable.
-        No-op when nothing is buffered (e.g. fsync="always")."""
-        if self._dirty and self._f is not None and not self._f.closed:
-            if obs.enabled():
-                t0 = time.perf_counter()
-                self._f.flush()
-                os.fsync(self._f.fileno())
-                obs.observe("f2_wal_fsync_seconds",
-                            time.perf_counter() - t0,
-                            help="group-commit fsync latency")
-            else:
-                self._f.flush()
-                os.fsync(self._f.fileno())
-            self._dirty = False
+        No-op when nothing is buffered (e.g. fsync="always").  The span's
+        `bytes` counts what was appended since the last sync."""
+        if self._unsynced and self._f is not None and not self._f.closed:
+            with obs.span("wal.sync", cat="durability",
+                          bytes=self._unsynced):
+                if obs.enabled():
+                    t0 = time.perf_counter()
+                    self._f.flush()
+                    os.fsync(self._f.fileno())
+                    obs.observe("f2_wal_fsync_seconds",
+                                time.perf_counter() - t0,
+                                help="group-commit fsync latency")
+                else:
+                    self._f.flush()
+                    os.fsync(self._f.fileno())
+            self._unsynced = 0
 
     def rotate(self, new_epoch: int):
         """Start segment `new_epoch`; called at the snapshot capture point
@@ -240,9 +249,9 @@ class WalWriter:
     def close(self):
         if self._f is not None and not self._f.closed:
             self._f.flush()
-            if self._dirty:             # clean segments are already durable
+            if self._unsynced:          # clean segments are already durable
                 os.fsync(self._f.fileno())
-                self._dirty = False
+                self._unsynced = 0
             self._f.close()
 
 

@@ -238,10 +238,11 @@ def probe(
     kw = dict(chain_max=cfg.chain_max, rc_match=rc_match, has_rc=has_rc,
               probe_index=probe_index, target=target)
     if engine == "fused_pallas":
-        out = probe_ops.fused_probe(*args, **kw)
+        out = probe_ops.fused_probe(*args, site=site, **kw)
     else:
         # the reference early-exits once every lane resolved (bit-exact)
-        out = fused_probe_reference(*args, early_exit=True, **kw)
+        with jax.named_scope(site):
+            out = fused_probe_reference(*args, early_exit=True, **kw)
     found, addr, heads_out, value, meta, hops, ios, exhausted = out
     n_io = jnp.sum(ios)
     return ProbeResult(found=found, addr=addr, heads=heads_out, value=value,

@@ -329,19 +329,22 @@ class ReplicatedKV(ShardedKV):
         primary replica.  Same contract as `ShardedKV.apply_round` — the
         session scheduler drives this entry under replication."""
         keys, ops, vals = self._coerce(keys, ops, vals)
-        if (self.wal is not None and not self._migrating
-                and not self._wal_defer and _rep_do is None):
-            # write-ahead, same rule as ShardedKV: client rounds only —
-            # masked resync/rebuild replay reconstructs already-logged
-            # data, and `apply` logs its whole batch itself
-            self.wal.log_slab(keys, ops, vals, self.map_version)
-        rep_do = np.asarray(self.alive if _rep_do is None else _rep_do, bool)
-        h = self._primary(rep_do)
-        (self.state, st_r, rv_r, placed, deferred,
-         occ, bc) = self._step(self.state, keys, ops, vals,
-                               self._bucket_map_dev, jnp.asarray(rep_do))
-        self._note_round(occ, bc)
-        self.maybe_compact()
+        with obs.span("replicated.apply_round", cat="serve",
+                      B=int(keys.shape[0]), round=self.rounds):
+            if (self.wal is not None and not self._migrating
+                    and not self._wal_defer and _rep_do is None):
+                # write-ahead, same rule as ShardedKV: client rounds only —
+                # masked resync/rebuild replay reconstructs already-logged
+                # data, and `apply` logs its whole batch itself
+                self.wal.log_slab(keys, ops, vals, self.map_version)
+            rep_do = np.asarray(self.alive if _rep_do is None else _rep_do,
+                                bool)
+            h = self._primary(rep_do)
+            (self.state, st_r, rv_r, placed, deferred,
+             occ, bc) = self._step(self.state, keys, ops, vals,
+                                   self._bucket_map_dev, jnp.asarray(rep_do))
+            self._note_round(occ, bc)
+            self.maybe_compact()
         return st_r[h], rv_r[h], placed, deferred
 
     def apply(self, keys, ops, vals=None, _rep_do=None):

@@ -461,20 +461,23 @@ class ShardedKV:
         to folding eagerly every round)."""
         if not self._pending:
             return
-        pending, self._pending = jax.device_get(self._pending), []
-        for occ_np, bc_np in pending:
-            self._routed_lanes += np.asarray(occ_np).astype(np.int64)
-            self._traffic_ewma = (self._decay * self._traffic_ewma
-                                  + np.asarray(bc_np))
-        if obs.enabled():       # mirror the folded traffic signal
-            obs.gauge_set("f2_bucket_traffic_ewma",
-                          self._traffic_ewma.tolist(),
-                          help="per-bucket routed-traffic EWMA",
-                          facade=self._obs_facade)
-            obs.gauge_set("f2_routed_lanes", self._routed_lanes.tolist(),
-                          help="cumulative routed lanes per shard",
-                          facade=self._obs_facade)
-            obs.rules.maybe_evaluate()  # alert pass at the fold point
+        with obs.span("sharded.fold", cat="serve",
+                      rounds=len(self._pending)):
+            pending, self._pending = jax.device_get(self._pending), []
+            for occ_np, bc_np in pending:
+                self._routed_lanes += np.asarray(occ_np).astype(np.int64)
+                self._traffic_ewma = (self._decay * self._traffic_ewma
+                                      + np.asarray(bc_np))
+            if obs.enabled():       # mirror the folded traffic signal
+                obs.gauge_set("f2_bucket_traffic_ewma",
+                              self._traffic_ewma.tolist(),
+                              help="per-bucket routed-traffic EWMA",
+                              facade=self._obs_facade)
+                obs.gauge_set("f2_routed_lanes",
+                              self._routed_lanes.tolist(),
+                              help="cumulative routed lanes per shard",
+                              facade=self._obs_facade)
+                obs.rules.maybe_evaluate()  # alert pass at the fold point
 
     @property
     def traffic_ewma(self) -> np.ndarray:
@@ -511,21 +514,22 @@ class ShardedKV:
         *batch*, not per round — callers run `maybe_rebalance()` at their
         own batch boundary."""
         keys, ops, vals = self._coerce(keys, ops, vals)
-        if (self.wal is not None and not self._migrating
-                and not self._wal_defer):
-            # write-ahead: the round's full input is durable before it
-            # executes (internal migration/resync replay is NOT logged —
-            # it reconstructs data the log already covers; `apply` logs
-            # its whole batch itself and re-derives the deferral rounds)
-            self.wal.log_slab(keys, ops, vals, self.map_version)
-        if self._ht is not None:
-            # pre-fault every host chunk this round would touch (routed
-            # writes cannot defer mid-step, exactly like KV.apply)
-            self.state = self._ht.ensure(
-                self.state, lambda st: self._plan_routed(
-                    st, keys, ops, vals, self._bucket_map_dev))
         with obs.span("sharded.apply_round", cat="serve",
-                      B=int(keys.shape[0])):
+                      B=int(keys.shape[0]), round=self.rounds):
+            if (self.wal is not None and not self._migrating
+                    and not self._wal_defer):
+                # write-ahead: the round's full input is durable before it
+                # executes (internal migration/resync replay is NOT logged
+                # — it reconstructs data the log already covers; `apply`
+                # logs its whole batch itself and re-derives the deferral
+                # rounds)
+                self.wal.log_slab(keys, ops, vals, self.map_version)
+            if self._ht is not None:
+                # pre-fault every host chunk this round would touch (routed
+                # writes cannot defer mid-step, exactly like KV.apply)
+                self.state = self._ht.ensure(
+                    self.state, lambda st: self._plan_routed(
+                        st, keys, ops, vals, self._bucket_map_dev))
             (self.state, status, rvals, placed, deferred,
              occ, bc) = self._step(self.state, keys, ops, vals,
                                    self._bucket_map_dev)
@@ -743,9 +747,10 @@ class ShardedKV:
     # -- vectorized pressure scheduler ---------------------------------------
     def _bounds(self):
         s = self.state
-        return [np.asarray(x).astype(np.int64) for x in jax.device_get(
-            (s.hot.begin, s.hot.tail, s.cold.begin, s.cold.tail,
-             s.cold_idx.begin, s.cold_idx.tail))]
+        with obs.span("compact.bounds", cat="compaction"):
+            return [np.asarray(x).astype(np.int64) for x in jax.device_get(
+                (s.hot.begin, s.hot.tail, s.cold.begin, s.cold.tail,
+                 s.cold_idx.begin, s.cold_idx.tail))]
 
     def hot_fills(self) -> np.ndarray:
         hb, ht, *_ = self._bounds()
@@ -1027,16 +1032,17 @@ class ShardedKV:
         if self.rounds - self._last_rb_round < rb.check_every:
             return False
         self._last_rb_round = self.rounds
-        new_map = rebalance.plan_moves(
-            self.traffic_ewma, self.bucket_map, self.S,
-            threshold=rb.threshold, max_moves=rb.max_moves,
-            min_traffic=rb.min_traffic,
-            fill=self._fill_signal() if rb.fill_weight > 0 else None,
-            fill_weight=rb.fill_weight)
-        if new_map is None:
-            return False
-        self.migrate(new_map)
-        return True
+        with obs.span("rebalance.check", cat="rebalance"):
+            new_map = rebalance.plan_moves(
+                self.traffic_ewma, self.bucket_map, self.S,
+                threshold=rb.threshold, max_moves=rb.max_moves,
+                min_traffic=rb.min_traffic,
+                fill=self._fill_signal() if rb.fill_weight > 0 else None,
+                fill_weight=rb.fill_weight)
+            if new_map is None:
+                return False
+            self.migrate(new_map)
+            return True
 
     def _fill_signal(self) -> np.ndarray:
         """Per-shard live-region record counts [S] — the occupancy half of

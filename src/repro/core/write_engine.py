@@ -125,12 +125,14 @@ def plan(
             rc.key, rc.val, rc.prev, rc.meta)
     if engine == "fused_pallas":
         out = probe_ops.fused_write(*args, log.begin, hb, ro, log.tail,
-                                    *cols, chain_max=cfg.chain_max)
+                                    *cols, chain_max=cfg.chain_max,
+                                    site=site)
     else:
         # the reference early-exits once every lane resolved (bit-exact)
-        out = fused_write_reference(*args, log.begin, hb, ro, log.tail,
-                                    *cols, chain_max=cfg.chain_max,
-                                    early_exit=True)
+        with jax.named_scope(site):
+            out = fused_write_reference(*args, log.begin, hb, ro, log.tail,
+                                        *cols, chain_max=cfg.chain_max,
+                                        early_exit=True)
     (rep, rep_pos, val_nocold, final_tomb, need_cold, created_nocold,
      found, addr, in_place, append, new_addrs, prevs, slots, publish,
      heads, rc_inval, hops, ios, exhausted) = out

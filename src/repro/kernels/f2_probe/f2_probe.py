@@ -305,14 +305,15 @@ def fused_probe(keys, heads_src, lower, active, head_boundary,
                 rc_key, rc_val, rc_prev, rc_meta, *,
                 chain_max: int, rc_match: bool = True, has_rc: bool = True,
                 probe_index: bool = True, target=None,
-                interpret: bool = False):
+                interpret: bool = False, site: str = "probe"):
     """Fused probe over a key batch.  Per-lane arrays are int32 [B]
     (`active` a 0/1 mask, `head_boundary` int32 [1]); the columns are the
     store's [C] / [C, V] log and [R] / [R, V] read-cache arrays (ring
     capacities, powers of two), laid out as VMEM rows here; `heads_src`
     is the hot index [E] (probe_index) or the per-lane heads [B].
     Returns (found, addr, heads, value [B*V], meta, hops, ios, exhausted),
-    every element int32."""
+    every element int32.  The call carries `{"f2_site": site}` as its
+    kernel metadata, so a profile tells the engine's call sites apart."""
     B = keys.shape[0]
     C, R = log_key.shape[0], rc_key.shape[0]
     V = log_val.shape[1]
@@ -336,6 +337,7 @@ def fused_probe(keys, heads_src, lower, active, head_boundary,
                    jax.ShapeDtypeStruct((B * V,), jnp.int32),
                    lane, lane, lane, lane],
         interpret=interpret,
+        metadata={"f2_site": site},
     )(keys, heads_in, lower, active, target, head_boundary,
       *(as_rows(c) for c in (log_key, log_val, log_prev, log_meta,
                              rc_key, rc_val, rc_prev, rc_meta)))
@@ -506,12 +508,14 @@ def _fused_write_kernel(keys_ref, ops_ref, vals_ref, bounds_ref, index_ref,
 def fused_write(keys, ops, vals, index, bounds,
                 log_key, log_val, log_prev, log_meta,
                 rc_key, rc_val, rc_prev, rc_meta, *,
-                chain_max: int, interpret: bool = False):
+                chain_max: int, interpret: bool = False,
+                site: str = "write.plan"):
     """Fused write-plan pass.  `bounds` packs the four scalars
     (begin, head_boundary, ro_addr, tail) as an int32 [4] array.  The whole
     batch is one call (intra-batch grouping needs every lane).  Returns
     the 19-tuple of `ref.fused_write_body` with `val_nocold` flattened to
-    [B*V], every element int32."""
+    [B*V], every element int32; `site` goes into the kernel metadata as
+    in `fused_probe`."""
     B = keys.shape[0]
     C, R = log_key.shape[0], rc_key.shape[0]
     V = log_val.shape[1]
@@ -534,6 +538,7 @@ def fused_write(keys, ops, vals, index, bounds,
                         pltpu.SMEM((B * V,), jnp.int32),
                         pltpu.SMEM((B,), jnp.int32)],
         interpret=interpret,
+        metadata={"f2_site": site},
     )(keys, ops, vals.reshape(-1), bounds, as_rows(index),
       *(as_rows(c) for c in (log_key, log_val, log_prev, log_meta,
                              rc_key, rc_val, rc_prev, rc_meta)))

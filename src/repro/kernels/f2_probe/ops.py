@@ -61,11 +61,12 @@ def fused_probe(keys, heads_src, lower, active, head_boundary,
                 rc_key, rc_val, rc_prev, rc_meta, *,
                 chain_max: int, rc_match: bool = True, has_rc: bool = True,
                 probe_index: bool = True, target=None,
-                interpret: bool | None = None):
+                interpret: bool | None = None, site: str = "probe"):
     """Callable under an outer jit.  Boolean masks in/out; pads B to a lane
     multiple and runs chunks of at most `B_TILE` lanes.  Returns (found,
     addr, heads, value, meta, hops, ios, exhausted) exactly like
-    `ref.fused_probe_reference`."""
+    `ref.fused_probe_reference`.  `site` (a `probe_engine.SITES` name)
+    goes into each kernel call's metadata."""
     itp = _interpret(interpret)
     B = keys.shape[0]
     V = log_val.shape[1]
@@ -84,7 +85,7 @@ def fused_probe(keys, heads_src, lower, active, head_boundary,
         return tuple(_fused_kernel(
             keys, heads, lower, active, hb, *cols, chain_max=chain_max,
             rc_match=rc_match, has_rc=has_rc, probe_index=probe_index,
-            target=target, interpret=itp))
+            target=target, interpret=itp, site=site))
 
     outs = []
     for s in range(0, Bp, B_TILE):
@@ -105,10 +106,12 @@ def fused_probe(keys, heads_src, lower, active, head_boundary,
 def fused_write(keys, ops, vals, index, begin, head_boundary, ro_addr, tail,
                 log_key, log_val, log_prev, log_meta,
                 rc_key, rc_val, rc_prev, rc_meta, *,
-                chain_max: int, interpret: bool | None = None):
+                chain_max: int, interpret: bool | None = None,
+                site: str = "write.plan"):
     """Callable under an outer jit.  Pads B up to a lane multiple with
     OP_NOOP lanes (inert: no grouping, no walk, no append).  Boolean masks
-    out; returns the 19-tuple of `ref.fused_write_body`."""
+    out; returns the 19-tuple of `ref.fused_write_body`.  `site` goes
+    into the kernel call's metadata."""
     itp = _interpret(interpret)
     B = keys.shape[0]
     V = vals.shape[1]
@@ -118,7 +121,7 @@ def fused_write(keys, ops, vals, index, begin, head_boundary, ro_addr, tail,
 
     def call(*args):
         return tuple(_fused_write_kernel(*args, chain_max=chain_max,
-                                         interpret=itp))
+                                         interpret=itp, site=site))
 
     out = _mapped_in_a_loop(
         call, _pad_to(keys, Bp), _pad_to(ops, Bp),  # 0 == OP_NOOP: inert

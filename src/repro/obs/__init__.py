@@ -5,14 +5,16 @@ Three pillars, one kill-switch:
 * `metrics`   — process-wide registry of Counters / Gauges / fixed-bucket
                 Histograms; every facade's `stats()` tree is re-backed by
                 it (`fold_stats`) while staying bit-compatible.
-* `trace`     — span tracer emitting Chrome-trace/Perfetto JSON over the
-                serving path, scheduler, migrations, resync and
-                checkpoint/WAL operations.
+* `trace`     — span tracer over the serving path, scheduler, WAL,
+                migrations, resync and checkpoints: Chrome-trace/Perfetto
+                JSON, and the JAX profiler's trace while one records.
 * `journal`   — bounded structured lifecycle event log fault-injection
                 tests assert sequences against.
 
 `configure(enabled=True)` arms all three; disabled (the default), every
-instrumentation site is a single flag check returning a shared no-op —
+instrumentation site is a single flag check returning a shared no-op (a
+span also asks whether a JAX profiler records, and annotates its trace
+if one does) —
 store behavior, state and `stats()` output are bit-exact with the
 pre-observability code.  Device-side signals are folded host-side at
 the stores' existing lazy folding points (`_fold_traffic`, `_fold_read`,
@@ -23,14 +25,14 @@ from . import _flags, export, journal, latency, metrics, rules, trace
 from .latency import observe_phase, observe_phase_many
 from .metrics import (COUNT_BUCKETS, LATENCY_BUCKETS, MetricError,
                       fold_stats, get_registry)
-from .trace import NOOP_SPAN, instant, span, traced
+from .trace import NOOP_SPAN, span
 
 __all__ = [
     "COUNT_BUCKETS", "LATENCY_BUCKETS", "MetricError", "NOOP_SPAN",
     "configure", "count", "enabled", "export", "fold_stats", "gauge_set",
-    "get_registry", "instant", "journal", "latency", "metrics", "observe",
+    "get_registry", "journal", "latency", "metrics", "observe",
     "observe_phase", "observe_phase_many", "reset_all", "rules", "span",
-    "trace", "traced",
+    "trace",
 ]
 
 

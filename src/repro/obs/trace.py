@@ -1,30 +1,43 @@
-"""Span tracer emitting Chrome-trace ("catapult") JSON.
+"""Span tracer: Chrome-trace ("catapult") JSON, and the JAX profiler's
+trace when one is recording.
 
-Spans cover the host-side serving path (`apply` -> `apply_round` ->
-deferral rounds), the pressure scheduler's compaction passes, bucket
-migrations, replica resync/rebuild, and checkpoint/WAL operations.  Load
-the saved file in `chrome://tracing` or Perfetto (`ui.perfetto.dev`).
+Spans cover the host-side serving path (session enqueue / poll / step,
+`apply_round`, deferral loops), the pressure scheduler's bounds reads and
+compaction passes, the WAL, bucket migrations, replica resync/rebuild,
+snapshots and recovery.
 
-API: `span(name, cat, **args)` is a context manager, `traced` the
-decorator form, `instant(name)` a zero-duration marker.  When
-observability is disabled every call returns the no-op singleton —
-no event object, no timestamp read, no allocation.
+API: `span(name, cat, **args)` is a context manager; it is the only
+span API.  Each span goes to up to two places:
 
-Events use the Chrome trace "complete" phase (`ph: "X"`): one record per
-span with microsecond `ts`/`dur` relative to tracer start.  The buffer
-is bounded; once full, new events are counted in `dropped` instead of
-growing without bound."""
+* while a JAX profiler session records (`jax.profiler.trace`,
+  `start_trace`), a `jax.profiler.TraceAnnotation` named `f2.<name>`
+  with `args` as its metadata: it lands on the trace's `/host:CPU`
+  plane, on the clock of the device ops, whether or not obs is armed;
+* while obs is armed (`obs.configure(enabled=True)`), the bounded
+  in-process buffer below, saved as Chrome JSON for `chrome://tracing`
+  or Perfetto (`ui.perfetto.dev`).
+
+With neither on, every call returns the shared no-op singleton: one
+flag check and one `TraceAnnotation.is_enabled()` call, no event object,
+no timestamp read, no allocation.
+
+Buffer events use the Chrome trace "complete" phase (`ph: "X"`): one
+record per span with microsecond `ts`/`dur` relative to tracer start.
+The buffer is bounded; once full, new events are counted in `dropped`
+instead of growing without bound."""
 from __future__ import annotations
 
-import functools
 import json
 import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 from . import _flags
 
 DEFAULT_CAPACITY = 200_000
+PROFILER_PREFIX = "f2."
 
 
 class _NoopSpan:
@@ -43,15 +56,18 @@ NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_ann")
 
-    def __init__(self, tracer, name, cat, args):
+    def __init__(self, tracer, name, cat, args, ann=None):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._ann = ann             # the profiler's annotation, if recording
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -64,6 +80,8 @@ class _Span:
             "pid": self._tracer._pid, "tid": threading.get_ident(),
             "args": self.args,
         })
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -85,13 +103,6 @@ class Tracer:
 
     def span(self, name: str, cat: str = "f2", **args) -> _Span:
         return _Span(self, name, cat, args)
-
-    def instant(self, name: str, cat: str = "f2", **args):
-        self._add({
-            "name": name, "cat": cat, "ph": "i", "s": "p",
-            "ts": (time.perf_counter_ns() - self._t0) / 1e3,
-            "pid": self._pid, "tid": threading.get_ident(), "args": args,
-        })
 
     def __len__(self):
         with self._lock:
@@ -120,29 +131,15 @@ TRACER = Tracer()
 
 
 def span(name: str, cat: str = "f2", **args):
-    """A traced region; the no-op singleton when obs is disabled."""
+    """A traced region: `f2.<name>` in the profiler's trace while one
+    records, an event in `TRACER` while obs is armed, the no-op singleton
+    otherwise.  Give only host values as `args` (ints, strings): the
+    caller evaluates them even when nothing records."""
+    profiling = TraceAnnotation.is_enabled()
     if not _flags.ENABLED:
+        if profiling:
+            return TraceAnnotation(PROFILER_PREFIX + name, **args)
         return NOOP_SPAN
-    return TRACER.span(name, cat, **args)
-
-
-def instant(name: str, cat: str = "f2", **args):
-    if not _flags.ENABLED:
-        return
-    TRACER.instant(name, cat, **args)
-
-
-def traced(name=None, cat: str = "f2"):
-    """Decorator form: `@traced()` spans the wrapped call by its
-    qualified name, `@traced("label")` by an explicit one."""
-    def deco(fn):
-        label = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            if not _flags.ENABLED:
-                return fn(*a, **kw)
-            with TRACER.span(label, cat):
-                return fn(*a, **kw)
-        return wrapper
-    return deco
+    return _Span(TRACER, name, cat, args,
+                 TraceAnnotation(PROFILER_PREFIX + name, **args)
+                 if profiling else None)

@@ -311,9 +311,9 @@ class KVSessionService:
         -> per-batch rebalance check.  With `sync=False` (the serving hot
         path) nothing round-trips to the host; `sync=True` returns the
         number of lanes packed (0 = the pool had nothing pending)."""
-        armed = obs.enabled()
-        t_pack0 = time.perf_counter() if armed else 0.0
-        with obs.span("sessions.step", cat="serve"):
+        with obs.span("sessions.step", cat="serve", round=self.pack_rounds):
+            armed = obs.enabled()
+            t_pack0 = time.perf_counter() if armed else 0.0
             (bkeys, bops, bvals, sess, slot, valid,
              fill) = self._pack_j(self.pool, self.kv._bucket_map_dev)
             t_pack1 = time.perf_counter() if armed else 0.0
@@ -334,20 +334,20 @@ class KVSessionService:
             snap = getattr(self.kv, "maybe_snapshot", None)
             if snap is not None:
                 snap()
-        self.pack_rounds += 1
-        self._pending_fill.append(fill)
-        if armed or self.trace_schedule:
-            tkt = self._round_tickets_j(self.pool, sess, slot, valid)
-            if armed:       # queued device-side; folded with the fills
-                self._clock.note_round(tkt, t_pack0, t_pack1, t_applied)
-            if self.trace_schedule:
-                self.schedule.append((sess, valid, bkeys, bops, bvals,
-                                      status, rvals, tkt))
-        if len(self._pending_fill) >= 128:
-            self._fold_fill()
-        if sync:
-            return int(np.asarray(jax.device_get(fill)).sum())
-        return None
+            self.pack_rounds += 1
+            self._pending_fill.append(fill)
+            if armed or self.trace_schedule:
+                tkt = self._round_tickets_j(self.pool, sess, slot, valid)
+                if armed:       # queued device-side; folded with the fills
+                    self._clock.note_round(tkt, t_pack0, t_pack1, t_applied)
+                if self.trace_schedule:
+                    self.schedule.append((sess, valid, bkeys, bops, bvals,
+                                          status, rvals, tkt))
+            if len(self._pending_fill) >= 128:
+                self._fold_fill()
+            if sync:
+                return int(np.asarray(jax.device_get(fill)).sum())
+            return None
 
     def run_until_idle(self, max_rounds: Optional[int] = None) -> int:
         """Pump packed rounds until the pool is empty of PENDING ops.
@@ -372,88 +372,97 @@ class KVSessionService:
 
     # -- internals driven by the Session handles ------------------------------
     def _enqueue(self, s: Session, keys, ops, vals):
-        keys = np.asarray(keys, np.int32)
-        ops = np.asarray(ops, np.int32)
-        if vals is None:
-            vals = np.zeros((len(keys), self.V), np.int32)
-        else:
-            vals = np.asarray(vals, np.int32)
-        assert keys.shape == ops.shape and vals.shape == keys.shape + (self.V,)
-        assert (ops != OP_NOOP).all(), \
-            "OP_NOOP cannot be enqueued (it would never complete)"
-        B = len(keys)
-        n_acc = min(B, self.depth - s.in_use)
-        t0 = self._next_ticket
-        self.pool = self._enqueue_j(
-            self.pool, jnp.int32(s.sid), jnp.asarray(keys),
-            jnp.asarray(ops), jnp.asarray(vals), jnp.int32(t0),
-            jnp.int32(n_acc))
-        self._next_ticket += n_acc
-        self.tickets_issued += n_acc
-        self.tickets_rejected += B - n_acc
-        if n_acc and obs.enabled():
-            self._clock.note_enqueue(t0, n_acc, time.perf_counter())
-        for i in range(n_acc):
-            t = t0 + i
-            s._slot_of[t] = s._tail + i     # monotone cursor, slot = mod C
-            s._fifo.append(t)
-        s._tail += n_acc
-        # tickets are host-deterministic: no device round-trip on enqueue
-        idx = np.arange(B, dtype=np.int32)
-        return np.where(idx < n_acc, t0 + idx, np.int32(-1)).astype(np.int32)
+        with obs.span("sessions.enqueue", cat="serve", sid=s.sid,
+                      ticket=self._next_ticket, n=len(keys)):
+            keys = np.asarray(keys, np.int32)
+            ops = np.asarray(ops, np.int32)
+            if vals is None:
+                vals = np.zeros((len(keys), self.V), np.int32)
+            else:
+                vals = np.asarray(vals, np.int32)
+            assert (keys.shape == ops.shape
+                    and vals.shape == keys.shape + (self.V,))
+            assert (ops != OP_NOOP).all(), \
+                "OP_NOOP cannot be enqueued (it would never complete)"
+            B = len(keys)
+            n_acc = min(B, self.depth - s.in_use)
+            t0 = self._next_ticket
+            self.pool = self._enqueue_j(
+                self.pool, jnp.int32(s.sid), jnp.asarray(keys),
+                jnp.asarray(ops), jnp.asarray(vals), jnp.int32(t0),
+                jnp.int32(n_acc))
+            self._next_ticket += n_acc
+            self.tickets_issued += n_acc
+            self.tickets_rejected += B - n_acc
+            if n_acc and obs.enabled():
+                self._clock.note_enqueue(t0, n_acc, time.perf_counter())
+            for i in range(n_acc):
+                t = t0 + i
+                s._slot_of[t] = s._tail + i     # monotone cursor, slot = mod C
+                s._fifo.append(t)
+            s._tail += n_acc
+            # tickets are host-deterministic: no device round-trip on enqueue
+            idx = np.arange(B, dtype=np.int32)
+            return np.where(idx < n_acc, t0 + idx,
+                            np.int32(-1)).astype(np.int32)
 
     def _state_row(self, s: Session) -> np.ndarray:
         """One session's slot states — the only device fetch a poll that
         finds nothing ready has to pay."""
-        return np.asarray(jax.device_get(self.pool.slot_state[s.sid]))
+        with obs.span("sessions.fetch", cat="serve"):
+            return np.asarray(jax.device_get(self.pool.slot_state[s.sid]))
 
     def _collect(self, s: Session, tickets: np.ndarray):
         """Collect the given tickets (all known-DONE): gather results,
         free slots, advance the host head mirror."""
-        C = self.depth
-        status, rvals = map(np.asarray, jax.device_get(
-            (self.pool.status[s.sid], self.pool.rvals[s.sid])))
-        mask = np.zeros(C, bool)
-        out_st = np.full(len(tickets), ST_NONE, np.int32)
-        out_v = np.zeros((len(tickets), self.V), np.int32)
-        for i, t in enumerate(tickets):
-            cur = s._slot_of.pop(int(t))
-            s._fifo.remove(int(t))
-            mask[cur % C] = True
-            out_st[i] = status[cur % C]
-            out_v[i] = rvals[cur % C]
-            s._freed.add(cur)
-        if mask.any():
-            self.pool = self._free_j(self.pool, jnp.int32(s.sid),
-                                     jnp.asarray(mask))
-            while s._head in s._freed:
-                s._freed.remove(s._head)
-                s._head += 1
-            self.collected += len(tickets)
-            if obs.enabled():   # collection is already a sync point
-                self._clock.note_collected(tickets, time.perf_counter())
-        return out_st, out_v
+        with obs.span("sessions.collect", cat="serve", n=len(tickets)):
+            C = self.depth
+            with obs.span("sessions.fetch", cat="serve"):
+                status, rvals = map(np.asarray, jax.device_get(
+                    (self.pool.status[s.sid], self.pool.rvals[s.sid])))
+            mask = np.zeros(C, bool)
+            out_st = np.full(len(tickets), ST_NONE, np.int32)
+            out_v = np.zeros((len(tickets), self.V), np.int32)
+            for i, t in enumerate(tickets):
+                cur = s._slot_of.pop(int(t))
+                s._fifo.remove(int(t))
+                mask[cur % C] = True
+                out_st[i] = status[cur % C]
+                out_v[i] = rvals[cur % C]
+                s._freed.add(cur)
+            if mask.any():
+                self.pool = self._free_j(self.pool, jnp.int32(s.sid),
+                                         jnp.asarray(mask))
+                while s._head in s._freed:
+                    s._freed.remove(s._head)
+                    s._head += 1
+                self.collected += len(tickets)
+                if obs.enabled():   # collection is already a sync point
+                    self._clock.note_collected(tickets, time.perf_counter())
+            return out_st, out_v
 
     def _poll(self, s: Session, tickets: np.ndarray):
-        state = self._state_row(s)
-        C = self.depth
-        done = np.zeros(len(tickets), bool)
-        ready = []
-        for i, t in enumerate(tickets):
-            cur = s._slot_of.get(int(t))
-            if cur is not None and state[cur % C] == SLOT_DONE:
-                done[i] = True
-                ready.append(int(t))
-        out_st = np.full(len(tickets), ST_NONE, np.int32)
-        out_v = np.zeros((len(tickets), self.V), np.int32)
-        if ready:
-            st_r, v_r = self._collect(s, np.asarray(ready))
-            j = 0
-            for i in range(len(tickets)):
-                if done[i]:
-                    out_st[i], out_v[i] = st_r[j], v_r[j]
-                    j += 1
-        return done, out_st, out_v
+        with obs.span("sessions.poll", cat="serve", sid=s.sid,
+                      n=len(tickets)):
+            state = self._state_row(s)
+            C = self.depth
+            done = np.zeros(len(tickets), bool)
+            ready = []
+            for i, t in enumerate(tickets):
+                cur = s._slot_of.get(int(t))
+                if cur is not None and state[cur % C] == SLOT_DONE:
+                    done[i] = True
+                    ready.append(int(t))
+            out_st = np.full(len(tickets), ST_NONE, np.int32)
+            out_v = np.zeros((len(tickets), self.V), np.int32)
+            if ready:
+                st_r, v_r = self._collect(s, np.asarray(ready))
+                j = 0
+                for i in range(len(tickets)):
+                    if done[i]:
+                        out_st[i], out_v[i] = st_r[j], v_r[j]
+                        j += 1
+            return done, out_st, out_v
 
     def _drain(self, s: Session):
         limit = self.total_outstanding() + self.N + 2
@@ -475,24 +484,27 @@ class KVSessionService:
     def _fold_fill(self):
         if not self._pending_fill:
             return
-        pending, self._pending_fill = jax.device_get(self._pending_fill), []
-        for f in pending:
-            f = np.asarray(f).astype(np.int64)
-            self._fill_sum += f
-            self._packed_lanes += int(f.sum())
-            self._fill_rounds += 1
-        if obs.enabled():       # mirror the folded packing signal
-            denom = self._fill_rounds * self.kv.S * self.W
-            obs.gauge_set("f2_slab_occupancy",
-                          self._packed_lanes / denom if denom else 0.0,
-                          help="mean fraction of slab lanes filled per "
-                               "packed round",
-                          facade=self._obs_facade)
-            obs.count_total("f2_packed_lanes_total", self._packed_lanes,
-                            help="lanes packed into routed rounds",
-                            facade=self._obs_facade)
-            self._clock.fold()          # queued ticket rounds ride along
-            obs.rules.maybe_evaluate()  # alert pass at the fold point
+        with obs.span("sessions.fold", cat="serve",
+                      rounds=len(self._pending_fill)):
+            pending = jax.device_get(self._pending_fill)
+            self._pending_fill = []
+            for f in pending:
+                f = np.asarray(f).astype(np.int64)
+                self._fill_sum += f
+                self._packed_lanes += int(f.sum())
+                self._fill_rounds += 1
+            if obs.enabled():       # mirror the folded packing signal
+                denom = self._fill_rounds * self.kv.S * self.W
+                obs.gauge_set("f2_slab_occupancy",
+                              self._packed_lanes / denom if denom else 0.0,
+                              help="mean fraction of slab lanes filled per "
+                                   "packed round",
+                              facade=self._obs_facade)
+                obs.count_total("f2_packed_lanes_total", self._packed_lanes,
+                                help="lanes packed into routed rounds",
+                                facade=self._obs_facade)
+                self._clock.fold()          # queued ticket rounds ride along
+                obs.rules.maybe_evaluate()  # alert pass at the fold point
 
     @property
     def packed_lanes(self) -> int:

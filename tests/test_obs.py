@@ -138,8 +138,6 @@ def test_disabled_span_is_shared_noop_singleton():
     with s1:
         pass
     assert len(obs.trace.TRACER) == 0
-    obs.instant("marker")
-    assert len(obs.trace.TRACER) == 0
 
 
 def test_disabled_fold_stats_is_identity():
@@ -175,10 +173,9 @@ CHROME_COMPLETE_KEYS = {"name", "cat", "ph", "ts", "dur", "pid", "tid",
 def _validate_chrome_trace(doc: dict):
     assert set(doc) == {"traceEvents", "displayTimeUnit", "otherData"}
     for ev in doc["traceEvents"]:
-        assert ev["ph"] in ("X", "i"), ev
-        if ev["ph"] == "X":
-            assert CHROME_COMPLETE_KEYS <= set(ev), ev
-            assert ev["ts"] >= 0 and ev["dur"] >= 0
+        assert ev["ph"] == "X", ev
+        assert CHROME_COMPLETE_KEYS <= set(ev), ev
+        assert ev["ts"] >= 0 and ev["dur"] >= 0
         assert isinstance(ev["pid"], int) and isinstance(ev["tid"], int)
         json.dumps(ev)                  # every event is JSON-able
 
@@ -187,7 +184,6 @@ def test_span_emits_chrome_complete_event(tmp_path):
     obs.configure(enabled=True)
     with obs.span("unit.work", cat="test", n=3):
         pass
-    obs.instant("unit.marker", cat="test")
     doc = obs.trace.TRACER.snapshot()
     _validate_chrome_trace(doc)
     ev = doc["traceEvents"][0]
@@ -198,16 +194,7 @@ def test_span_emits_chrome_complete_event(tmp_path):
         _validate_chrome_trace(json.load(f))
 
 
-def test_traced_decorator_and_capacity_bound():
-    obs.configure(enabled=True)
-
-    @obs.traced("unit.fn", cat="test")
-    def fn(x):
-        return x + 1
-
-    assert fn(1) == 2
-    assert obs.trace.TRACER.snapshot()["traceEvents"][-1]["name"] == "unit.fn"
-
+def test_tracer_capacity_bound():
     tr = Tracer(capacity=4)
     for i in range(6):
         with tr.span(f"s{i}"):
@@ -225,6 +212,108 @@ def test_store_run_produces_valid_trace():
     names = {ev["name"] for ev in doc["traceEvents"]}
     assert "sharded.apply_round" in names
     _validate_chrome_trace(doc)
+
+
+# -- spans in the JAX profiler's trace ----------------------------------------
+
+def _profiled_spans(trace_dir) -> list:
+    """(name, args, start_ns, end_ns) of every `f2.*` event on the host
+    plane of the one `.xplane.pb` under `trace_dir`."""
+    import glob
+    from jax.profiler import ProfileData
+    paths = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    assert len(paths) == 1, paths
+    out = []
+    for plane in ProfileData.from_file(paths[0]).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            out.extend((e.name, dict(e.stats), int(e.start_ns),
+                        int(e.end_ns)) for e in line.events
+                       if e.name.startswith("f2."))
+    return out
+
+
+def _inside(child, parent) -> bool:
+    return parent[2] <= child[2] and child[3] <= parent[3]
+
+
+def test_spans_reach_the_profiler_trace_without_obs(tmp_path):
+    """With obs off and a profiler recording, a routed round's spans land
+    on the trace's host plane as `f2.<name>` with their args (the round's
+    bounds read nested in it), and nothing reaches the Chrome buffer."""
+    import jax
+    kv = ShardedKV(tiny_cfg(), 2, trigger=0.6, compact_batch=64,
+                   donate=False)
+    keys = np.arange(1, B + 1, dtype=np.int32)
+    vals = np.zeros((B, V), np.int32)
+    kv.upsert(keys, vals)                       # compiled outside the trace
+    rounds = kv.rounds
+    with jax.profiler.trace(str(tmp_path)):
+        assert obs.span("x") is not NOOP_SPAN
+        kv.upsert(keys, vals + 1)
+    assert obs.span("x") is NOOP_SPAN
+    assert len(obs.trace.TRACER) == 0
+    spans = _profiled_spans(tmp_path)
+    apply = [s for s in spans if s[0] == "f2.sharded.apply_round"]
+    assert [s[1] for s in apply] == [{"B": B, "round": rounds}]
+    bounds = [s for s in spans if s[0] == "f2.compact.bounds"]
+    assert bounds and all(s[1] == {} for s in bounds)
+    assert _inside(bounds[0], apply[0])
+
+
+def test_session_round_spans_nest_in_the_profiler_trace(tmp_path):
+    """Enqueue, step and poll of the session service, through a durable
+    store, each under its own span: the fetches and the collection inside
+    the poll, the WAL append inside the routed round, the group commit
+    inside the step; with obs armed the buffer records them too."""
+    import jax
+
+    from repro.core.durability import DurabilityConfig, DurableKV
+    from repro.serve.sessions import KVSessionService
+    svc = KVSessionService(DurableKV(_sharded(), DurabilityConfig(
+        dir=str(tmp_path / "wal"))), max_sessions=2, session_depth=64)
+    s = svc.open_session()
+    keys = np.arange(1, 17, dtype=np.int32)
+    ops = np.full(16, OP_UPSERT, np.int32)
+
+    def one_round():
+        tk = s.enqueue(keys, ops, np.ones((16, V), np.int32))
+        svc.step()
+        done, _, _ = s.poll(tk)
+        assert done.all()
+        return int(tk[0])
+
+    one_round()                                 # compiled outside the trace
+    obs.configure(enabled=True)
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        first = one_round()
+    spans = _profiled_spans(tmp_path / "trace")
+    by = {}
+    for sp in spans:
+        by.setdefault(sp[0], []).append(sp)
+    (enq,) = by["f2.sessions.enqueue"]
+    assert enq[1] == {"sid": s.sid, "ticket": first, "n": 16}
+    (step,) = by["f2.sessions.step"]
+    assert step[1] == {"round": svc.pack_rounds - 1}
+    (poll,) = by["f2.sessions.poll"]
+    assert poll[1] == {"sid": s.sid, "n": 16}
+    (collect,) = by["f2.sessions.collect"]
+    assert collect[1] == {"n": 16} and _inside(collect, poll)
+    fetches = by["f2.sessions.fetch"]
+    assert len(fetches) == 2 and all(_inside(f, poll) for f in fetches)
+    assert sum(_inside(f, collect) for f in fetches) == 1
+    (apply,) = by["f2.sharded.apply_round"]
+    (log,) = by["f2.wal.log_slab"]
+    (sync,) = by["f2.wal.sync"]
+    assert _inside(apply, step) and _inside(log, apply)
+    assert _inside(sync, step) and not _inside(sync, apply)
+    assert log[1]["bytes"] == sync[1]["bytes"] > 16 * 4 * (2 + V)
+    assert all(_inside(b, apply) for b in by["f2.compact.bounds"])
+    buffered = {ev["name"] for ev in obs.trace.TRACER.snapshot()[
+        "traceEvents"]}
+    assert {name[3:] for name in by} <= buffered
+    svc.kv.close()
 
 
 # ---------------------------------------------------------------------------

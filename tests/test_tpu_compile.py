@@ -139,6 +139,32 @@ def test_kernels_compile_at_odd_batch(one_chip, no_persistent_cache, batch):
              + [sds(())] * 4 + _cols(sds, C, R), batch)
 
 
+@pytest.mark.parametrize("kernel,site", [("probe", "read.cold"),
+                                         ("write", "write.plan")])
+def test_kernel_carries_its_site(one_chip, no_persistent_cache, kernel,
+                                 site):
+    """Each kernel call names its engine site (`probe_engine.SITES`) in
+    its metadata, so the custom call a profile records says which site
+    ran it."""
+    sds = _sds(one_chip)
+    B, C, R, E = 128, 1 << 12, 1 << 10, 1 << 12
+    if kernel == "probe":
+        fn = lambda *a: kops.fused_probe(                   # noqa: E731
+            *a, chain_max=CHAIN_MAX, interpret=False, site=site)
+        args = [sds((B,)), sds((E,)), sds((B,)), sds((B,), jnp.bool_),
+                sds(())] + _cols(sds, C, R)
+    else:
+        fn = lambda *a: kops.fused_write(                   # noqa: E731
+            *a, chain_max=CHAIN_MAX, interpret=False, site=site)
+        args = [sds((B,)), sds((B,)), sds((B, V)), sds((E,))] \
+            + [sds(())] * 4 + _cols(sds, C, R)
+    lowered = jax.jit(fn).lower(*args)
+    text = lowered.as_text()
+    assert "kernel_metadata" in text and f"f2_site\\22:\\22{site}" in text
+    hlo = lowered.compile().as_text()
+    assert "tpu_custom_call" in hlo and f'"f2_site":"{site}"' in hlo
+
+
 def test_resolve_refuses_kernel_past_budget(monkeypatch):
     """On a TPU, "fused" takes the kernel only inside both budgets and
     says why; a forced kernel past a budget raises.  Two stores of
