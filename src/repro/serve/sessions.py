@@ -19,6 +19,13 @@ per-session `head`/`tail` cursors and a per-slot lane state
 (FREE -> PENDING -> DONE -> FREE).  Enqueue, completion scatter and slot
 collection are all jitted scatters on that one structure.
 
+The host keeps a mirror of the pool's completion state (`slot_state`,
+`status`, `rvals`).  Only the completion scatter in `step()` changes it in
+a way the host cannot know; enqueue (-> PENDING) and collection (-> FREE)
+write through to the mirror.  So polls and drains fetch the pool at most
+once per round, whichever session asks first, and every other poll reads
+the mirror without touching the device.
+
 Scheduler
 ---------
 `step()` runs one routed round: the jitted packer
@@ -164,9 +171,10 @@ class Session:
         self.open = True
         self._head = 0                  # host mirrors of the device cursors
         self._tail = 0
-        self._freed: set = set()        # collected cursors ahead of head
-        self._slot_of: dict = {}        # outstanding ticket -> cursor
-        self._fifo: list = []           # outstanding tickets, enqueue order
+        # outstanding tickets in enqueue order (ascending) and their ring
+        # cursors (slot = cursor mod C)
+        self._tk = np.zeros(0, np.int64)
+        self._cur = np.zeros(0, np.int64)
 
     @property
     def capacity(self) -> int:
@@ -179,7 +187,11 @@ class Session:
     @property
     def outstanding(self) -> int:
         """Ops enqueued and not yet collected (pending or done)."""
-        return len(self._fifo)
+        return len(self._tk)
+
+    def outstanding_tickets(self) -> np.ndarray:
+        """The tickets enqueued and not yet collected, in enqueue order."""
+        return self._tk.copy()
 
     def enqueue(self, keys, ops, vals=None) -> np.ndarray:
         """Submit a batch; returns one int32 ticket per lane, -1 for
@@ -244,6 +256,14 @@ class KVSessionService:
         self.collected = 0
         self.pack_rounds = 0
         self.sessions_opened = 0
+        self.pool_fetches = 0                   # device->host pool fetches
+        self.polls = 0
+        # host mirror of (slot_state, status, rvals); the pool starts all
+        # zeros (every slot FREE), so the mirror starts fresh
+        self._mirror = (np.zeros((self.N, self.depth), np.int32),
+                        np.zeros((self.N, self.depth), np.int32),
+                        np.zeros((self.N, self.depth, self.V), np.int32))
+        self._mirror_stale = False
         self._pending_fill: list = []           # unfolded per-round fill [S]
         self._packed_lanes = 0                  # folded totals
         self._fill_rounds = 0
@@ -325,6 +345,12 @@ class KVSessionService:
             # result
             self.pool = self._commit_j(self.pool, sess, slot,
                                        valid & placed, status, rvals)
+            # the round's DONE marks exist only on the device: queue their
+            # transfer behind the commit for the next poll's fetch
+            self._mirror_stale = True
+            for a in (self.pool.slot_state, self.pool.status,
+                      self.pool.rvals):
+                a.copy_to_host_async()
             t_applied = time.perf_counter() if armed else 0.0
             self.kv.maybe_rebalance()
             # durability hook: a DurableKV backing store snapshots on its
@@ -394,91 +420,109 @@ class KVSessionService:
             self._next_ticket += n_acc
             self.tickets_issued += n_acc
             self.tickets_rejected += B - n_acc
-            if n_acc and obs.enabled():
-                self._clock.note_enqueue(t0, n_acc, time.perf_counter())
-            for i in range(n_acc):
-                t = t0 + i
-                s._slot_of[t] = s._tail + i     # monotone cursor, slot = mod C
-                s._fifo.append(t)
+            if n_acc:
+                if obs.enabled():
+                    self._clock.note_enqueue(t0, n_acc, time.perf_counter())
+                lane = np.arange(n_acc, dtype=np.int64)
+                s._tk = np.concatenate([s._tk, t0 + lane])
+                s._cur = np.concatenate([s._cur, s._tail + lane])
+                self._mirror[0][s.sid, (s._tail + lane) % self.depth] = \
+                    SLOT_PENDING
             s._tail += n_acc
             # tickets are host-deterministic: no device round-trip on enqueue
             idx = np.arange(B, dtype=np.int32)
             return np.where(idx < n_acc, t0 + idx,
                             np.int32(-1)).astype(np.int32)
 
-    def _state_row(self, s: Session) -> np.ndarray:
-        """One session's slot states — the only device fetch a poll that
-        finds nothing ready has to pay."""
-        with obs.span("sessions.fetch", cat="serve"):
-            return np.asarray(jax.device_get(self.pool.slot_state[s.sid]))
-
-    def _collect(self, s: Session, tickets: np.ndarray):
-        """Collect the given tickets (all known-DONE): gather results,
-        free slots, advance the host head mirror."""
-        with obs.span("sessions.collect", cat="serve", n=len(tickets)):
-            C = self.depth
+    def _completion(self):
+        """The host mirror (slot_state, status, rvals), fetched from the
+        device only if a round has committed since the last fetch."""
+        if self._mirror_stale:
             with obs.span("sessions.fetch", cat="serve"):
-                status, rvals = map(np.asarray, jax.device_get(
-                    (self.pool.status[s.sid], self.pool.rvals[s.sid])))
+                self._mirror = tuple(np.array(a) for a in jax.device_get(
+                    (self.pool.slot_state, self.pool.status,
+                     self.pool.rvals)))
+            self._mirror_stale = False
+            self.pool_fetches += 1
+            obs.count("f2_sessions_pool_fetches_total",
+                      help="device->host fetches of the session pool",
+                      facade=self._obs_facade)
+        return self._mirror
+
+    def _all_done(self, s: Session) -> bool:
+        if not len(s._tk):
+            return True
+        state = self._completion()[0]
+        return bool((state[s.sid, s._cur % self.depth] == SLOT_DONE).all())
+
+    def _collect(self, s: Session, pos: np.ndarray):
+        """Collect the outstanding ops at positions `pos` of `s._tk`
+        (distinct, all DONE in a fresh mirror): gather their results,
+        free their slots, advance head.  Returns (tickets, status, vals)
+        in the order of `pos`."""
+        with obs.span("sessions.collect", cat="serve", n=len(pos)):
+            C = self.depth
+            state, status, rvals = self._mirror
+            tickets = s._tk[pos]
+            slot = s._cur[pos] % C
+            st, v = status[s.sid, slot], rvals[s.sid, slot]
+            state[s.sid, slot] = SLOT_FREE
             mask = np.zeros(C, bool)
-            out_st = np.full(len(tickets), ST_NONE, np.int32)
-            out_v = np.zeros((len(tickets), self.V), np.int32)
-            for i, t in enumerate(tickets):
-                cur = s._slot_of.pop(int(t))
-                s._fifo.remove(int(t))
-                mask[cur % C] = True
-                out_st[i] = status[cur % C]
-                out_v[i] = rvals[cur % C]
-                s._freed.add(cur)
-            if mask.any():
-                self.pool = self._free_j(self.pool, jnp.int32(s.sid),
-                                         jnp.asarray(mask))
-                while s._head in s._freed:
-                    s._freed.remove(s._head)
-                    s._head += 1
-                self.collected += len(tickets)
-                if obs.enabled():   # collection is already a sync point
-                    self._clock.note_collected(tickets, time.perf_counter())
-            return out_st, out_v
+            mask[slot] = True
+            self.pool = self._free_j(self.pool, jnp.int32(s.sid),
+                                     jnp.asarray(mask))
+            # _free_kernel's rule: head advances over the contiguous FREE
+            # prefix of the in-use window (the mirror's FREE marks are
+            # exact even when it is stale: the device frees nothing itself)
+            free = state[s.sid, (s._head + np.arange(s.in_use)) % C] \
+                == SLOT_FREE
+            s._head += len(free) if free.all() else int(np.argmin(free))
+            keep = np.ones(len(s._tk), bool)
+            keep[pos] = False
+            s._tk, s._cur = s._tk[keep], s._cur[keep]
+            self.collected += len(pos)
+            if obs.enabled():
+                self._clock.note_collected(tickets, time.perf_counter())
+            return tickets, st, v
 
     def _poll(self, s: Session, tickets: np.ndarray):
         with obs.span("sessions.poll", cat="serve", sid=s.sid,
                       n=len(tickets)):
-            state = self._state_row(s)
-            C = self.depth
-            done = np.zeros(len(tickets), bool)
-            ready = []
-            for i, t in enumerate(tickets):
-                cur = s._slot_of.get(int(t))
-                if cur is not None and state[cur % C] == SLOT_DONE:
-                    done[i] = True
-                    ready.append(int(t))
-            out_st = np.full(len(tickets), ST_NONE, np.int32)
-            out_v = np.zeros((len(tickets), self.V), np.int32)
-            if ready:
-                st_r, v_r = self._collect(s, np.asarray(ready))
-                j = 0
-                for i in range(len(tickets)):
-                    if done[i]:
-                        out_st[i], out_v[i] = st_r[j], v_r[j]
-                        j += 1
+            self.polls += 1
+            k = len(tickets)
+            done = np.zeros(k, bool)
+            out_st = np.full(k, ST_NONE, np.int32)
+            out_v = np.zeros((k, self.V), np.int32)
+            n = len(s._tk)
+            if not (n and k):
+                return done, out_st, out_v
+            state = self._completion()[0]
+            # tickets rise within a session: unknown, rejected (-1) and
+            # collected tickets miss the sorted outstanding array
+            pos = np.minimum(np.searchsorted(s._tk, tickets), n - 1)
+            hit = (s._tk[pos] == tickets) & (
+                state[s.sid, s._cur[pos] % self.depth] == SLOT_DONE)
+            cand = np.flatnonzero(hit)
+            if len(cand):
+                # a ticket listed twice is answered at its first listing
+                pos, first = np.unique(pos[cand], return_index=True)
+                sel = cand[first]
+                done[sel] = True
+                _, out_st[sel], out_v[sel] = self._collect(s, pos)
             return done, out_st, out_v
 
     def _drain(self, s: Session):
         limit = self.total_outstanding() + self.N + 2
         for _ in range(limit):
-            state = self._state_row(s)
-            C = self.depth
-            if all(state[cur % C] == SLOT_DONE
-                   for cur in s._slot_of.values()):
+            if self._all_done(s):
                 break
             self.step()
         else:
             raise RuntimeError("drain made no progress")
-        tickets = np.asarray(sorted(s._fifo), np.int64)
-        st, v = self._collect(s, tickets) if len(tickets) else (
-            np.zeros(0, np.int32), np.zeros((0, self.V), np.int32))
-        return tickets, st, v
+        if not len(s._tk):
+            return (np.zeros(0, np.int64), np.zeros(0, np.int32),
+                    np.zeros((0, self.V), np.int32))
+        return self._collect(s, np.arange(len(s._tk)))
 
     # -- slab-occupancy telemetry (the bench's before/after signal) ----------
     def _fold_fill(self):
@@ -540,7 +584,7 @@ class KVSessionService:
         B = len(keys)
         status = np.zeros(B, np.int32)
         rvals = np.zeros((B, self.V), np.int32)
-        lane_of = {}
+        lane_of = ([], [])                      # (tickets, lanes) per chunk
         start = 0
         while start < B:
             live = ops[start:] != OP_NOOP       # NOOP lanes complete as
@@ -556,19 +600,26 @@ class KVSessionService:
             if not sel.all():
                 n = int(np.argmin(sel))         # stop chunk at first NOOP
                 chunk = slice(nxt, nxt + n)
-            tk = s.enqueue(keys[chunk], ops[chunk], vals[chunk])
-            for j, t in enumerate(tk):
-                lane_of[int(t)] = nxt + j
+            lane_of[0].append(s.enqueue(keys[chunk], ops[chunk],
+                                        vals[chunk]))
+            lane_of[1].append(np.arange(nxt, nxt + n))
             start = nxt + n
         self._drain_into(s, lane_of, status, rvals)
         return jnp.asarray(status), jnp.asarray(rvals)
 
     def _drain_into(self, s, lane_of, status, rvals):
+        """Drain the private session; its outstanding ops are exactly the
+        chunks in `lane_of`, in ticket order."""
         tk, st, v = s.drain()
-        for j, t in enumerate(tk):
-            lane = lane_of.pop(int(t))
-            status[lane] = st[j]
-            rvals[lane] = v[j]
+        if not len(tk):
+            return
+        assert np.array_equal(np.concatenate(lane_of[0]), tk), \
+            "sync session drained tickets it did not enqueue"
+        lane = np.concatenate(lane_of[1])
+        status[lane] = st
+        rvals[lane] = v
+        lane_of[0].clear()
+        lane_of[1].clear()
 
     def read(self, keys):
         ops = np.full(len(keys), OP_READ, np.int32)
@@ -606,6 +657,8 @@ class KVSessionService:
             collected=self.collected,
             outstanding=self.total_outstanding(),
             pack_rounds=self.pack_rounds,
+            polls=self.polls,
+            pool_fetches=self.pool_fetches,
             packed_lanes=self.packed_lanes,
             slab_occupancy=round(self.slab_occupancy(), 4),
         )
@@ -621,16 +674,24 @@ class KVSessionService:
     def check_invariants(self):
         """Store invariants plus pool/bookkeeping coherence: device
         cursors match the host mirrors, in-use windows fit the rings,
-        and every PENDING slot belongs to an outstanding ticket."""
+        every PENDING slot belongs to an outstanding ticket, the host
+        mirror's FREE marks match the pool's, and a fresh mirror equals
+        the pool's completion state."""
         self.kv.check_invariants()
-        head, tail, state = jax.device_get(
-            (self.pool.head, self.pool.tail, self.pool.slot_state))
-        head, tail = np.asarray(head), np.asarray(tail)
+        head, tail, state, status, rvals = map(np.asarray, jax.device_get(
+            (self.pool.head, self.pool.tail, self.pool.slot_state,
+             self.pool.status, self.pool.rvals)))
+        assert np.array_equal(self._mirror[0] == SLOT_FREE,
+                              state == SLOT_FREE), "FREE mirror drift"
+        if not self._mirror_stale:
+            for name, host, dev in zip(("slot_state", "status", "rvals"),
+                                       self._mirror, (state, status, rvals)):
+                assert np.array_equal(host, dev), (name, "pool mirror drift")
         for sid, s in enumerate(self._sessions):
             if s is None:
                 continue
             assert s._head == int(head[sid]), (sid, "head mirror drift")
             assert s._tail == int(tail[sid]), (sid, "tail mirror drift")
             assert 0 <= s.in_use <= self.depth, (sid, "ring overflow")
-            n_live = int((np.asarray(state[sid]) != SLOT_FREE).sum())
-            assert n_live == len(s._slot_of), (sid, "slot bookkeeping drift")
+            n_live = int((state[sid] != SLOT_FREE).sum())
+            assert n_live == len(s._tk), (sid, "slot bookkeeping drift")

@@ -178,9 +178,9 @@ def drive(svc, sessions, rng, n_events, n_keys, enq_log, results):
                                        vals[i].copy())
         elif act == "step":
             svc.step()
-        elif act == "poll" and s._fifo:
-            pick = rng.choice(s._fifo, size=min(len(s._fifo), 4),
-                              replace=False)
+        elif act == "poll" and s.outstanding:
+            out = s.outstanding_tickets()
+            pick = rng.choice(out, size=min(len(out), 4), replace=False)
             done, st, v = s.poll(pick)
             for i, t in enumerate(pick):
                 if done[i]:
@@ -275,6 +275,207 @@ def test_session_interleaving_seeded():
     check_session_interleaving(44, N=1, C=12)
 
 
+# ---------------------------------------------------------------------------
+# poll/drain against a per-ticket reference (the host mirror of the pool)
+# ---------------------------------------------------------------------------
+
+def drive_logged(svc, sessions, rng, n_events, n_keys, enq_log, log):
+    """Random enqueue/step/poll/drain interleaving whose polls mix a
+    session's outstanding tickets with unknown, rejected (-1), other
+    sessions' and already-collected ones (sometimes listed twice).  Every
+    poll and drain is logged with the tickets issued and the rounds run
+    before it; the invariants are checked after every event."""
+    collected = []
+    for _ in range(n_events):
+        act = rng.choice(["enq", "enq", "step", "poll", "poll", "drain"])
+        s = sessions[int(rng.integers(0, len(sessions)))]
+        issued = max(enq_log, default=-1) + 1
+        if act == "enq":
+            keys, ops, vals = mixed_enqueue(rng, n_keys,
+                                            int(rng.integers(1, 7)))
+            tk = s.enqueue(keys, ops, vals)
+            for i, t in enumerate(tk):
+                if t >= 0:
+                    enq_log[int(t)] = (s.sid, int(keys[i]), int(ops[i]),
+                                       vals[i].copy())
+            # a fresh slot reads PENDING until a round runs, also where it
+            # held a DONE op collected since the last round
+            issued = max(enq_log, default=-1) + 1
+            got = s.poll(tk)
+            assert not got[0].any()
+            log.append(("poll", issued, len(svc.schedule), s.sid, tk, got))
+        elif act == "step":
+            svc.step()
+        elif act == "poll":
+            out = s.outstanding_tickets()
+            pick = list(rng.choice(out, size=min(len(out), 5),
+                                   replace=False)) if len(out) else []
+            junk = [-1, issued + 1000]
+            others = [x for x in sessions if x is not s and x.outstanding]
+            if others:
+                junk.append(int(others[0].outstanding_tickets()[0]))
+            if collected:
+                junk.append(int(rng.choice(collected)))
+            if pick and rng.random() < 0.3:
+                junk.append(int(pick[0]))       # listed twice
+            tickets = np.asarray(pick + junk, np.int64)
+            rng.shuffle(tickets)
+            got = s.poll(tickets)
+            collected += [int(t) for t in tickets[got[0]]]
+            log.append(("poll", issued, len(svc.schedule), s.sid, tickets,
+                        got))
+        elif act == "drain":
+            got = s.drain()
+            collected += [int(t) for t in got[0]]
+            log.append(("drain", issued, len(svc.schedule), s.sid, None,
+                        got))
+        svc.check_invariants()
+
+
+def check_log_against_rounds(svc, enq_log, log):
+    """The per-ticket reference for every logged call: a poll answers
+    exactly the tickets of its session that ran in an earlier round and
+    were not collected before (each at its first listing), with that
+    round's status and values, and ST_NONE/zeros elsewhere; a drain
+    returns every uncollected ticket of its session in FIFO order."""
+    ran = {}                            # ticket -> (round, status, vals)
+    for r, (sess, valid, _, _, _, status, rvals,
+            tkt) in enumerate(jax.device_get(svc.schedule)):
+        for i in np.flatnonzero(np.asarray(valid)):
+            ran[int(tkt[i])] = (r, int(status[i]), np.asarray(rvals[i]))
+    collected = set()
+    for kind, issued, rounds, sid, tickets, (a, st, v) in log:
+        if kind == "poll":
+            for i, t in enumerate(int(t) for t in tickets):
+                ready = (0 <= t < issued and enq_log[t][0] == sid
+                         and t not in collected and t in ran
+                         and ran[t][0] < rounds)
+                assert a[i] == ready, (kind, rounds, t)
+                if ready:
+                    collected.add(t)
+                    assert st[i] == ran[t][1], (rounds, t)
+                    assert np.array_equal(v[i], ran[t][2]), (rounds, t)
+                else:
+                    assert st[i] == 0 and not v[i].any(), (rounds, t)
+        else:
+            want = [t for t in range(issued)
+                    if enq_log[t][0] == sid and t not in collected]
+            assert list(a) == want, (kind, rounds, sid)
+            for i, t in enumerate(want):
+                assert ran[t][0] < rounds, (rounds, t)
+                assert st[i] == ran[t][1], (rounds, t)
+                assert np.array_equal(v[i], ran[t][2]), (rounds, t)
+            collected.update(want)
+    return {t: (ran[t][1], ran[t][2]) for t in collected}
+
+
+@pytest.mark.parametrize("seed", [101, 202, 303])
+def test_poll_drain_reference_with_ring_wrap(seed):
+    """Depth-8 rings that wrap many times, slots reused between two
+    steps, and polls of unknown, rejected, foreign, repeated and
+    collected tickets: every poll and drain result matches the per-ticket
+    reference, the twin replay holds, and so do the invariants (the host
+    mirror against the pool) after every event."""
+    C = 8
+    svc, cfg, store_kw = make_service(S=2, W=4, N=3, C=C, trigger=0.6)
+    sessions = [svc.open_session() for _ in range(3)]
+    rng = np.random.default_rng(seed)
+    enq_log, log = {}, []
+    # the reuse case by construction: fill a ring, run and collect it,
+    # refill the same slots before the next round, and poll them
+    s = sessions[0]
+    for _ in range(2):
+        keys, ops, vals = mixed_enqueue(rng, 150, C)
+        tk = s.enqueue(keys, ops, vals)
+        for i, t in enumerate(tk):
+            enq_log[int(t)] = (s.sid, int(keys[i]), int(ops[i]), vals[i])
+        issued = max(enq_log) + 1
+        log.append(("poll", issued, len(svc.schedule), s.sid, tk,
+                    s.poll(tk)))
+        svc.run_until_idle()
+        log.append(("poll", issued, len(svc.schedule), s.sid, tk,
+                    s.poll(tk)))
+        assert log[-1][-1][0].all() and log[-2][-1][0].sum() == 0
+        svc.check_invariants()
+    drive_logged(svc, sessions, rng, 150, 150, enq_log, log)
+    for s in sessions:
+        got = s.drain()
+        log.append(("drain", max(enq_log) + 1, len(svc.schedule), s.sid,
+                    None, got))
+    svc.check_invariants()
+    assert sum(1 for e in log if e[0] == "drain") > len(sessions)
+    assert max(enq_log) + 1 > 6 * C             # the rings wrapped
+    results = check_log_against_rounds(svc, enq_log, log)
+    assert len(results) == len(enq_log)         # each collected once
+    verify_history(svc, cfg, store_kw, 2, 4, enq_log, results, [],
+                   ("reference", seed))
+
+
+@pytest.mark.parametrize("n_sessions", [1, 8])
+def test_pool_fetched_once_per_round(n_sessions):
+    """Polls of every session after one step() fetch the pool once; polls
+    with no step in between, and polls before any step, fetch nothing."""
+    svc, _, _ = make_service(S=2, W=2, N=n_sessions, C=16)
+    sessions = [svc.open_session() for _ in range(n_sessions)]
+    rng = np.random.default_rng(n_sessions)
+    # more ops than three rounds of 2 x 2 lanes run
+    tks = [s.enqueue(*mixed_enqueue(rng, 64, 14)) for s in sessions]
+
+    def poll_all():
+        for s, tk in zip(sessions, tks):
+            s.poll(tk)
+        return svc.stats()["sessions"]["pool_fetches"]
+
+    assert poll_all() == 0
+    svc.step()
+    assert poll_all() == 1
+    assert poll_all() == 1
+    svc.step()
+    svc.step()
+    assert poll_all() == 2
+    assert svc.stats()["sessions"]["polls"] == 4 * n_sessions
+    for s in sessions:
+        s.drain()
+    svc.check_invariants()
+
+
+def test_pool_fetches_bounded_by_rounds_in_serving_loop():
+    """The serving loop's shape (chunks offered round-robin to 8 sessions
+    with room, one step, a poll of every session's outstanding tickets):
+    one pool fetch a round, and at most rounds plus drains overall."""
+    N, C, chunk = 8, 32, 8
+    svc, _, _ = make_service(S=2, W=16, N=N, C=C)
+    svc.trace_schedule = False
+    sessions = [svc.open_session() for _ in range(N)]
+    pend = [np.zeros(0, np.int64) for _ in sessions]
+    rng = np.random.default_rng(9)
+    rr = 0
+    for _ in range(40):
+        for _ in range(int(rng.integers(1, 4))):
+            room = [i for i in range(N)
+                    if sessions[(rr + i) % N].capacity
+                    - sessions[(rr + i) % N].in_use >= chunk]
+            if not room:
+                break
+            i = (rr + room[0]) % N
+            rr = (i + 1) % N
+            tk = sessions[i].enqueue(*mixed_enqueue(rng, 200, chunk))
+            pend[i] = np.concatenate([pend[i], tk.astype(np.int64)])
+        svc.step()
+        for i, s in enumerate(sessions):
+            if len(pend[i]):
+                done, _, _ = s.poll(pend[i])
+                pend[i] = pend[i][~done]
+    st = svc.stats()["sessions"]
+    assert st["pool_fetches"] == st["pack_rounds"] == 40
+    for s in sessions:
+        s.drain()
+    st = svc.stats()["sessions"]
+    assert st["pool_fetches"] <= st["pack_rounds"] + N
+    assert st["outstanding"] == 0
+    svc.check_invariants()
+
+
 def test_session_over_replicated_store():
     """The session layer runs unchanged over `ReplicatedKV`: packed
     cross-session rounds fan in to every alive replica (replicas stay
@@ -366,8 +567,8 @@ def check_liveness(seed, S=2, W=4, N=3, C=8, rounds=30):
             assert state[pos] == SLOT_DONE, \
                 (seed, t, "oldest pending ticket starved")
         for s in sessions:
-            if rng.random() < 0.5 and s._fifo:
-                s.poll(list(s._fifo))
+            if rng.random() < 0.5 and s.outstanding:
+                s.poll(list(s.outstanding_tickets()))
     for s in sessions:
         s.drain()
     svc.check_invariants()
@@ -404,7 +605,7 @@ def test_no_starvation_under_hot_shard_flood():
         svc.step()
         done, st, _ = b.poll(tb)
         # collect A's completions and immediately reflood with NEW tickets
-        a.poll(list(a._fifo))
+        a.poll(list(a.outstanding_tickets()))
         flood(C)
         if done.all():
             done_round = r + 1
